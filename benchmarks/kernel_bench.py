@@ -114,12 +114,10 @@ def run():
     # Shapes are block multiples and stay modest, and the section's x64 jit
     # caches are dropped afterwards — they are one-shot here, and the whole
     # quick suite runs under a guarded peak-RSS ceiling (benchmarks.compare)
-    from jax.experimental import enable_x64
-
     from repro.kernels.dp_layer import dp_layer_program
 
     params = (1.0, 1.0, 5.0, 20)
-    with enable_x64():
+    with jax.enable_x64(True):
         for B, R, C in ((8, 256, 128), (8, 384, 128)):
             cost_a = rng.uniform(1, 100, (B, R, C))
             cost_b = rng.uniform(1, 100, (B, R, C))

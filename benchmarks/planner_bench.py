@@ -21,7 +21,7 @@ from benchmarks.common import fixture, geomean
 from repro.core.cost import CostModel
 from repro.core.decomposition import decompose
 from repro.core.join_order import dp_join_order, dp_join_order_ref
-from repro.core.planner import OdysseyOptimizer
+from repro.core.planner import OdysseyOptimizer, plans_equal
 from repro.core.source_selection import select_sources
 from repro.query.algebra import BGPQuery, Const, TriplePattern, Var
 
@@ -204,7 +204,7 @@ def run_batch(scale: float = 1.0, size: int = BATCH_SIZE, reps: int = 5,
 
     plans_l, plans_b = loop(), batched()
     for q, a, b in zip(batch, plans_l, plans_b):
-        assert _plan_equal(a, b), f"batched plan differs from loop: {q.name}"
+        assert plans_equal(a, b), f"batched plan differs from loop: {q.name}"
 
     loop_ms = _median_ms(loop, reps)
     batch_ms = _median_ms(batched, reps)
@@ -232,21 +232,6 @@ def run_batch(scale: float = 1.0, size: int = BATCH_SIZE, reps: int = 5,
             f"{speedup:.1f}x the sequential loop at batch {len(batch)} "
             f"(need >= {MIN_BATCH_SPEEDUP}x)\n{text}")
     return csv, text, metrics
-
-
-def _plan_equal(a, b) -> bool:
-    from repro.core.planner import JoinPlanNode, SubqueryNode
-
-    def shape(n):
-        if isinstance(n, SubqueryNode):
-            return ("sq", tuple(n.stars), tuple(n.sources), n.est_cardinality,
-                    tuple((tp.s, tp.p, tp.o) for tp in n.patterns))
-        assert isinstance(n, JoinPlanNode)
-        return ("join", n.strategy, tuple(n.join_vars), n.est_cardinality,
-                shape(n.left), shape(n.right))
-
-    return shape(a.root) == shape(b.root) and \
-        a.selection.star_sources == b.selection.star_sources
 
 
 def run(scale: float = 1.0, reps: int = 9, seeds_per_size: int = 2):

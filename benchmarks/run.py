@@ -49,6 +49,9 @@ def main() -> None:
     args = ap.parse_args()
     scale = args.scale if args.scale is not None else (0.25 if args.quick else 1.0)
 
+    from repro.common.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import fedbench_figs as F
     from benchmarks import (
         adaptive_bench,

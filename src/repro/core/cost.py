@@ -167,7 +167,7 @@ class CostModel:
 
     # -- jax twins (broadcasting; used by the on-device layer sweep) ---------
     # jax is imported lazily so the numpy planning path never pays for it;
-    # callers (repro.kernels.dp_layer) run under jax.experimental.enable_x64
+    # callers (repro.kernels.dp_layer) run under jax.enable_x64(True)
     # so every formula evaluates in float64, exactly like the numpy forms.
 
     def leaf_cost_jnp(self, card, n_src, src_w):

@@ -38,10 +38,11 @@ Two DP implementations share the same plan space and cost model:
                        — host enumerates the topology's layer schedule once,
                        the DP state stays on device across layers) whenever
                        the schedule fits the tile budget, falling back to
-                       the per-layer Pallas kernel otherwise, with identical
-                       enumeration order and first-strict-minimum
-                       tie-breaking, so the two backends return
-                       bit-identical plans.
+                       the per-layer Pallas kernel otherwise (CPU backend
+                       only; elsewhere ``TiledSweepUnsupportedError``),
+                       with identical enumeration order and
+                       first-strict-minimum tie-breaking, so the two
+                       backends return bit-identical plans.
 ``dp_join_order_ref``  the original frozenset/`itertools.combinations`
                        formulation with unmemoized statistics, kept as the
                        reference oracle — tests assert the bitmask DP returns
@@ -304,6 +305,15 @@ _STRAT_SINGLE, _STRAT_EXCL, _STRAT_HASH, _STRAT_BIND = 1, 2, 3, 4
 # (schedule too large for the memory budget, or n too big for int32 masks).
 DP_SWEEP_COUNTERS = {"resident": 0, "tiled": 0,
                      "schedule_builds": 0, "schedule_hits": 0}
+
+
+class TiledSweepUnsupportedError(RuntimeError):
+    """A ``dp_backend='jax'`` sweep whose layer schedule does not fit the
+    resident device program, on a backend other than the CPU.  The tiled
+    path prices through a float64 ``pallas_call``, which a TPU cannot
+    compile, so the planner refuses instead of running it in interpret mode
+    or in numpy behind the caller's back."""
+
 
 # Resident sweeps ship int32 mask indices; past this star count the dense
 # 2^n state wouldn't fit a sane budget anyway (the roadmap's hash-indexed
@@ -808,12 +818,23 @@ def _dp_sweep(
             resident = True
             DP_SWEEP_COUNTERS["resident"] += 1
         else:
+            from repro.kernels import interpret_mode
+
+            if not interpret_mode():
+                sched_size = ("over the resident bound" if sched is None else
+                              f"{sched.n_pairs} candidate pairs, "
+                              f"{sched.nbytes} schedule bytes")
+                raise TiledSweepUnsupportedError(
+                    f"the join-order DP for {n} stars x {B} members "
+                    f"({sched_size}) exceeds the resident device program's "
+                    f"{budget}-byte budget, and its tiled float64 Pallas "
+                    "path cannot compile for this backend")
             DP_SWEEP_COUNTERS["tiled"] += 1
     if not resident:
         ctx = contextlib.nullcontext()
         if dp_backend == "jax":
-            from jax.experimental import enable_x64
-            ctx = enable_x64()
+            import jax
+            ctx = jax.enable_x64(True)
         with ctx:
             _tiled_layer_sweep(cm, dp_backend, n, B, tile_elems, masks, adj,
                                conn, card, cost, bindable, n_src, src_w,

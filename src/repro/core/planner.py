@@ -173,6 +173,25 @@ class PhysicalPlan:
         return self.selection.pattern_source_count(self.graph)
 
 
+def plans_equal(a: PhysicalPlan, b: PhysicalPlan) -> bool:
+    """Two conjunctive plans are the same plan: identical join trees
+    (strategies, join variables, estimated cardinalities, subqueries and
+    their sources) and identical per-star source selections."""
+
+    def shape(n: PlanNode) -> tuple:
+        if isinstance(n, SubqueryNode):
+            return ("sq", tuple(n.stars), tuple(n.sources), n.est_cardinality,
+                    tuple((tp.s, tp.p, tp.o) for tp in n.patterns))
+        if not isinstance(n, JoinPlanNode):
+            raise TypeError(f"plans_equal compares conjunctive plans; got a "
+                            f"{type(n).__name__}")
+        return ("join", n.strategy, tuple(n.join_vars), n.est_cardinality,
+                shape(n.left), shape(n.right))
+
+    return shape(a.root) == shape(b.root) and \
+        a.selection.star_sources == b.selection.star_sources
+
+
 # --------------------------------------------------------------------------
 # Plan cache
 # --------------------------------------------------------------------------

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import time
 import warnings
+from collections import Counter
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -443,7 +444,12 @@ def _naive_group(eng: LocalEngine, src: Source, node: GroupNode) -> Relation:
     return {v: c[m] for v, c in rel.items()}
 
 
-def naive_evaluate(fed: Federation, query: BGPQuery) -> set[tuple[int, ...]]:
+def naive_evaluate(fed: Federation, query: BGPQuery,
+                   bag: bool = False) -> "set[tuple[int, ...]] | Counter":
+    """The exact answer of ``query`` over the union of the sources: the set
+    of projected rows, or with ``bag=True`` their multiset (a ``Counter``
+    of rows; each row once under DISTINCT), as SPARQL's bag semantics
+    counts a projection without DISTINCT."""
     from repro.rdf.dataset import TripleTable
 
     s = np.concatenate([src.table.s for src in fed.sources])
@@ -466,4 +472,7 @@ def naive_evaluate(fed: Federation, query: BGPQuery) -> set[tuple[int, ...]]:
     proj = query.effective_projection()
     n = _nrows(rel)
     cols = [rel.get(v, np.full(n, fill, np.int32)) for v in proj]
-    return set(zip(*[c.tolist() for c in cols])) if n else set()
+    rows = list(zip(*[c.tolist() for c in cols])) if n else []
+    if not bag:
+        return set(rows)
+    return Counter(set(rows) if query.distinct else rows)

@@ -5,8 +5,9 @@ capacity relations:
 
     rel = (data: (CAP, NCOLS) int32, valid: (CAP,) bool, overflow: bool[])
 
-Rows beyond the live count are zeroed and invalid. Overflow flags propagate so
-the host can retry with a doubled capacity (the engine's fallback path).
+Rows beyond the live count are zeroed and invalid. Overflow flags propagate to
+the host, which runs the plan again with a doubled capacity
+(``DistributedEngine.execute``) and never keeps a truncated result.
 Compaction uses stable sorts instead of gathers-with-dynamic-shapes; joins use
 the counts/offsets construction that ``kernels/join_count`` accelerates.
 """
@@ -16,10 +17,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-
-
-def make_rel(cap: int, ncols: int):
-    return (jnp.zeros((cap, ncols), jnp.int32), jnp.zeros(cap, bool), jnp.zeros((), bool))
 
 
 def compact(mask: jax.Array, cap: int) -> tuple[jax.Array, jax.Array, jax.Array]:
@@ -117,11 +114,6 @@ def distinct(rel: jax.Array, valid: jax.Array, cap: int):
     m = v & first
     idx, vv, ovf = compact(m, cap)
     return jnp.where(vv[:, None], r[idx], 0), vv, ovf
-
-
-@jax.jit
-def count_valid(valid: jax.Array) -> jax.Array:
-    return jnp.sum(valid.astype(jnp.int32))
 
 
 # --------------------------------------------------------------------------

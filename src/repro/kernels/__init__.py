@@ -1,8 +1,9 @@
 """Pallas TPU kernels for the statistics/engine hot spots.
 
 Each kernel has a pure-jnp oracle in ``ref.py`` and a jit'd public wrapper in
-``ops.py``. All kernels are validated in ``interpret=True`` mode on CPU and
-written with MXU/VPU-aligned block shapes for TPU as the target:
+``ops.py``. Kernels are written with MXU/VPU-aligned block shapes for TPU as
+the target; they run compiled on an accelerator and in Pallas interpret mode
+on the CPU backend, where the tests validate them (``interpret_mode``):
 
   * ``sorted_intersect`` -- multiplicity-weighted intersection count of sorted
     id lists (Algorithm 1's inner loop);
@@ -16,3 +17,13 @@ written with MXU/VPU-aligned block shapes for TPU as the target:
     first-strict-minimum reduction, gridded over (member, column tile, row
     tile); float64, bit-identical to the numpy sweep (``dp_backend='jax'``).
 """
+
+
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run in interpret mode: exactly when the default
+    backend is the CPU, which has no compiled Pallas.  The single place this
+    is decided — an accelerator always gets the compiled kernel, never a
+    silent interpreter."""
+    import jax
+
+    return jax.default_backend() == "cpu"

@@ -24,13 +24,15 @@ strict minimum per subset.  Two device entry points live here:
 
 ``dp_layer``
     The original per-tile Pallas kernel (grid over ``(member, column tile,
-    row tile)``), kept for the tiled fallback path — layer tiles too large
-    for a resident schedule under the memory budget — and as the TPU
-    mapping of the layer step.  ``interpret=True`` is the CPU default like
-    every kernel in this package.
+    row tile)``), kept for the tiled path on the CPU backend — layer tiles
+    too large for a resident schedule under the memory budget.  It prices
+    in float64, which a TPU ``pallas_call`` cannot compile, so the planner
+    refuses that path on an accelerator
+    (``join_order.TiledSweepUnsupportedError``) instead of running it
+    anywhere else.
 
 Both entries price in float64 (callers run under
-``jax.experimental.enable_x64``) and reproduce the numpy sweep's
+``jax.enable_x64(True)``) and reproduce the numpy sweep's
 enumeration order and first-strict-minimum tie-breaking bit for bit; the
 cost-model parameters are **traced** ``(4,)`` inputs, so one compiled
 program serves every ``CostModel`` — a parameter sweep (``kernel_bench``,
@@ -44,8 +46,9 @@ from collections import OrderedDict
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 from jax.experimental import pallas as pl
+
+from repro.kernels import interpret_mode
 
 BLOCK_R = 128
 BLOCK_C = 128
@@ -200,17 +203,18 @@ def _build_layer_program(interpret: bool):
     return call
 
 
-def dp_layer_program(params: tuple, interpret: bool = True):
+def dp_layer_program(params: tuple):
     """The jitted device-level entry: expects pre-padded arrays whose row /
     column extents are block multiples (see ``_bucket``), ``float64`` pair
     state and ``int8`` masks, and returns the raw padded outputs.  ``params``
     is passed on every call as a traced ``(4,)`` array, so the returned
     program is shared across cost models.  Run it under
-    ``jax.experimental.enable_x64``; benchmarks time this directly so the
+    ``jax.enable_x64(True)``; benchmarks time this directly so the
     Pallas side is a jitted call on device arrays exactly like the jitted
     oracle — not the host wrapper with its padding logic."""
-    fn = PROGRAM_CACHE.get(("layer", bool(interpret)),
-                           lambda: _build_layer_program(bool(interpret)))
+    interpret = interpret_mode()
+    fn = PROGRAM_CACHE.get(("layer", interpret),
+                           lambda: _build_layer_program(interpret))
     # repro: ignore[RPR005] -- the docstring contract requires callers to run
     # the returned program under enable_x64; building the params array f64
     # here would silently truncate to f32 only if that contract is broken
@@ -219,7 +223,7 @@ def dp_layer_program(params: tuple, interpret: bool = True):
 
 
 def dp_layer(cost_a, cost_b, card_a, n_src_b, src_w_b, bindable, valid,
-             card_s, params: tuple, interpret: bool = True):
+             card_s, params: tuple):
     """Price one dense layer tile and reduce it per column.
 
     Inputs are the per-pair gathers described in ``ref.dp_layer_ref`` (same
@@ -240,7 +244,7 @@ def dp_layer(cost_a, cost_b, card_a, n_src_b, src_w_b, bindable, valid,
     f64 = np.float64
 
     def run():
-        call = dp_layer_program(params, interpret)
+        call = dp_layer_program(params)
         if valid.shape == (R_p, C_p):
             valid_p = np.asarray(valid, np.int8)
         else:
@@ -256,7 +260,7 @@ def dp_layer(cost_a, cost_b, card_a, n_src_b, src_w_b, bindable, valid,
 
     if jax.config.jax_enable_x64:
         return run()
-    with enable_x64():
+    with jax.enable_x64(True):
         return run()
 
 
@@ -398,5 +402,5 @@ def dp_sweep_resident(params: tuple, pair_a, pair_b, pair_seg, layer_cols,
 
     if jax.config.jax_enable_x64:
         return run()
-    with enable_x64():
+    with jax.enable_x64(True):
         return run()

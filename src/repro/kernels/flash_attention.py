@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 NEG_INF = -1e30
 
 
@@ -65,8 +67,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int = 0,
-                    block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    block_q: int = 128, block_k: int = 128) -> jax.Array:
     """q: (BH, S, hd) with per-q-head k/v already broadcast: k, v: (BH, S, hd).
     Scaling (hd^-0.5) is the caller's job. Returns (BH, S, hd)."""
     BH, S, hd = q.shape
@@ -91,5 +92,5 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, 1), jnp.float32),    # running denom
             pltpu.VMEM((block_q, hd), jnp.float32),   # output accumulator
         ],
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(q, k, v)

@@ -11,6 +11,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 BLOCK_P = 256
 BLOCK_B = 256
 
@@ -29,8 +31,7 @@ def _kernel(p_ref, b_ref, bw_ref, out_ref):
     out_ref[...] += jnp.sum(jnp.where(eq, bw, 0), axis=1, keepdims=True).astype(jnp.int32)
 
 
-def join_count(probe: jax.Array, build: jax.Array, build_w: jax.Array,
-               interpret: bool = True) -> jax.Array:
+def join_count(probe: jax.Array, build: jax.Array, build_w: jax.Array) -> jax.Array:
     """probe: (NP,) int32; build: (NB,) sorted int32 (pad with weight 0).
     Returns (NP,) int32 match multiplicities."""
     np_, nb = probe.shape[0], build.shape[0]
@@ -46,6 +47,6 @@ def join_count(probe: jax.Array, build: jax.Array, build_w: jax.Array,
         ],
         out_specs=pl.BlockSpec((BLOCK_P, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((np_, 1), jnp.int32),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(probe.reshape(-1, 1), build.reshape(1, -1), build_w.reshape(1, -1))
     return out[:, 0]

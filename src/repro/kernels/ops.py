@@ -1,6 +1,6 @@
 """Public jit'd wrappers: padding, dtype handling, and host-friendly entry
-points for the Pallas kernels. ``interpret`` defaults to True (CPU container);
-a TPU deployment flips it to False via ``set_interpret``.
+points for the Pallas kernels. Each kernel runs compiled on an accelerator and in
+interpret mode on the CPU backend (``repro.kernels.interpret_mode``).
 
 Host-coercion audit (``repro.analysis`` RPR001): every ``int(...)`` /
 ``np.asarray(...)`` in this module sits in an *untraced* host entry point —
@@ -24,13 +24,6 @@ from repro.kernels.seg_bitmap import BLOCK_N as SB_BN, BLOCK_S as SB_BS, NBUCKET
 from repro.kernels.sorted_intersect import BLOCK_A as SI_BA, BLOCK_B as SI_BB, sorted_intersect_weighted
 from repro.kernels.summary_probe import BLOCK_A as SP_BA, BLOCK_B as SP_BB, BLOCK_W as SP_BW, summary_probe
 
-_INTERPRET = True
-
-
-def set_interpret(flag: bool) -> None:
-    global _INTERPRET
-    _INTERPRET = flag
-
 
 def _pad_to(x: np.ndarray | jax.Array, mult: int, fill) -> jax.Array:
     n = x.shape[0]
@@ -46,7 +39,7 @@ def intersect_count(a, aw, b, bw) -> int:
     aw = _pad_to(np.asarray(aw, np.int32), SI_BA, 0)
     b = _pad_to(np.asarray(b, np.int32), SI_BB, -2)
     bw = _pad_to(np.asarray(bw, np.int32), SI_BB, 0)
-    return int(sorted_intersect_weighted(a, aw, b, bw, interpret=_INTERPRET))
+    return int(sorted_intersect_weighted(a, aw, b, bw))
 
 
 def predicate_bitmaps(seg, bucket, n_seg) -> np.ndarray:
@@ -54,7 +47,7 @@ def predicate_bitmaps(seg, bucket, n_seg) -> np.ndarray:
     seg = _pad_to(np.asarray(seg, np.int32), SB_BN, -1)
     bucket = _pad_to(np.asarray(bucket, np.int32), SB_BN, 0)
     n_seg_p = n_seg + ((-n_seg) % SB_BS)
-    counts = seg_bitmap(seg, bucket, n_seg_p, interpret=_INTERPRET)
+    counts = seg_bitmap(seg, bucket, n_seg_p)
     return np.asarray(counts[:n_seg] > 0)
 
 
@@ -64,7 +57,7 @@ def match_counts(probe, build, build_w) -> np.ndarray:
     p = _pad_to(np.asarray(probe, np.int32), JC_BP, -1)
     b = _pad_to(np.asarray(build, np.int32), JC_BB, -2)
     w = _pad_to(np.asarray(build_w, np.int32), JC_BB, 0)
-    return np.asarray(join_count(p, b, w, interpret=_INTERPRET))[:n]
+    return np.asarray(join_count(p, b, w))[:n]
 
 
 def signature_overlap(a_sig, b_sig) -> np.ndarray:
@@ -77,7 +70,7 @@ def signature_overlap(a_sig, b_sig) -> np.ndarray:
     na, nb = a32.shape[0], b32.shape[0]
     a32 = _pad2(a32, SP_BA, SP_BW)
     b32 = _pad2(b32, SP_BB, SP_BW)
-    out = summary_probe(jnp.asarray(a32), jnp.asarray(b32), interpret=_INTERPRET)
+    out = summary_probe(jnp.asarray(a32), jnp.asarray(b32))
     return np.asarray(out)[:na, :nb]
 
 
@@ -92,8 +85,7 @@ def flash_attention_gqa(q, k, v, *, causal=True, window=0):
     kb = jnp.repeat(k, G, axis=2).transpose(0, 2, 1, 3).reshape(B * H, S, hd)
     vb = jnp.repeat(v, G, axis=2).transpose(0, 2, 1, 3).reshape(B * H, S, hd)
     qb = (q * hd ** -0.5).transpose(0, 2, 1, 3).reshape(B * H, S, hd)
-    out = flash_attention(qb, kb, vb, causal=causal, window=window,
-                          interpret=_INTERPRET)
+    out = flash_attention(qb, kb, vb, causal=causal, window=window)
     return out.reshape(B, H, S, hd).transpose(0, 2, 1, 3)
 
 
@@ -101,7 +93,7 @@ def selective_scan(dt, bt, ct, x, a, chunk: int = 64):
     """Chunked Mamba selective scan (see kernels/ssm_scan.py)."""
     from repro.kernels.ssm_scan import ssm_scan
 
-    return ssm_scan(dt, bt, ct, x, a, chunk=chunk, interpret=_INTERPRET)
+    return ssm_scan(dt, bt, ct, x, a, chunk=chunk)
 
 
 def _u64_to_i32(x: np.ndarray) -> np.ndarray:

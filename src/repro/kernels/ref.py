@@ -52,7 +52,7 @@ def dp_layer_ref(cost_a, cost_b, card_a, n_src_b, src_w_b, bindable, valid,
     is valid), the first row attaining it (rows ascend in the reference
     enumeration order, so first == the numpy DP's first-strict-minimum
     tie-breaking) and whether that candidate is a bind join.  Runs in
-    float64 — call under ``jax.experimental.enable_x64``."""
+    float64 — call under ``jax.enable_x64(True)``."""
     iw, tw, rc, bb = params
     hash_s = iw * card_s
     hc = (cost_a + cost_b) + hash_s[:, None, :]

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 BLOCK_N = 256       # rows (s,p) per step
 BLOCK_S = 128       # segments per output tile
 NBUCKETS = 128      # predicate hash buckets (one MXU lane tile)
@@ -42,8 +44,7 @@ def _kernel(seg_ref, bkt_ref, out_ref):
     )
 
 
-def seg_bitmap(seg: jax.Array, bucket: jax.Array, n_seg: int,
-               interpret: bool = True) -> jax.Array:
+def seg_bitmap(seg: jax.Array, bucket: jax.Array, n_seg: int) -> jax.Array:
     """seg: (N,) sorted int32 segment ids (pad -1); bucket: (N,) int32 in
     [0, NBUCKETS). Returns (n_seg, NBUCKETS) float32 *counts* per (segment,
     bucket); callers binarize for the OR semantics."""
@@ -59,5 +60,5 @@ def seg_bitmap(seg: jax.Array, bucket: jax.Array, n_seg: int,
         ],
         out_specs=pl.BlockSpec((BLOCK_S, NBUCKETS), lambda s, n: (s, 0)),
         out_shape=jax.ShapeDtypeStruct((n_seg, NBUCKETS), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(seg.reshape(-1, 1), bucket.reshape(-1, 1))

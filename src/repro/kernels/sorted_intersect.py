@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 BLOCK_A = 256
 BLOCK_B = 256
 
@@ -38,8 +40,8 @@ def _kernel(a_ref, aw_ref, b_ref, bw_ref, out_ref):
     out_ref[0, 0] += jnp.sum(jnp.where(eq, w, 0), dtype=jnp.int32)
 
 
-def sorted_intersect_weighted(a: jax.Array, aw: jax.Array, b: jax.Array, bw: jax.Array,
-                              interpret: bool = True) -> jax.Array:
+def sorted_intersect_weighted(a: jax.Array, aw: jax.Array, b: jax.Array,
+                              bw: jax.Array) -> jax.Array:
     """a, b: sorted int32 ids, padded to multiples of the block sizes with
     weight-0 entries. Returns scalar int32 Σ_{a_i == b_j} aw_i · bw_j."""
     na, nb = a.shape[0], b.shape[0]
@@ -56,6 +58,6 @@ def sorted_intersect_weighted(a: jax.Array, aw: jax.Array, b: jax.Array, bw: jax
         ],
         out_specs=pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(a.reshape(-1, 1), aw.reshape(-1, 1), b.reshape(1, -1), bw.reshape(1, -1))
     return out[0, 0]

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 CHUNK = 64
 BLOCK_D = 256
 
@@ -52,8 +54,7 @@ def _kernel(dt_ref, bt_ref, ct_ref, x_ref, a_ref, y_ref, h_ref, *,
 
 
 def ssm_scan(dt: jax.Array, bt: jax.Array, ct: jax.Array, x: jax.Array,
-             a: jax.Array, *, chunk: int = CHUNK, block_d: int = BLOCK_D,
-             interpret: bool = True) -> jax.Array:
+             a: jax.Array, *, chunk: int = CHUNK, block_d: int = BLOCK_D) -> jax.Array:
     """dt, x: (B, S, D) f32; bt, ct: (B, S, N) f32; a: (D, N) f32 (negative).
     Returns y: (B, S, D) f32. S % chunk == 0, D % block_d == 0."""
     B, S, D = x.shape
@@ -74,5 +75,5 @@ def ssm_scan(dt: jax.Array, bt: jax.Array, ct: jax.Array, x: jax.Array,
         out_specs=pl.BlockSpec((1, chunk, block_d), lambda b, d, c: (b, c, d)),
         out_shape=jax.ShapeDtypeStruct((B, S, D), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_d, N), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(dt, bt, ct, x, a)

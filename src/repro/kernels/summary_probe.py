@@ -12,6 +12,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 BLOCK_A = 128
 BLOCK_B = 128
 BLOCK_W = 8        # signature words per step
@@ -40,7 +42,7 @@ def _kernel(a_ref, b_ref, out_ref):
     out_ref[...] += acc
 
 
-def summary_probe(a_sig: jax.Array, b_sig: jax.Array, interpret: bool = True) -> jax.Array:
+def summary_probe(a_sig: jax.Array, b_sig: jax.Array) -> jax.Array:
     """a_sig: (nA, W) int32 words; b_sig: (nB, W). Returns (nA, nB) int32
     popcount of the pairwise AND (0 ⇔ definitely-no-overlap)."""
     na, w = a_sig.shape
@@ -56,5 +58,5 @@ def summary_probe(a_sig: jax.Array, b_sig: jax.Array, interpret: bool = True) ->
         ],
         out_specs=pl.BlockSpec((BLOCK_A, BLOCK_B), lambda i, j, w: (i, j)),
         out_shape=jax.ShapeDtypeStruct((na, nb), jnp.int32),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(a_sig, b_sig)

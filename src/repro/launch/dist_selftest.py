@@ -1,12 +1,14 @@
 """Distributed-engine self-test: run the federated workload through the
-shard_map executor on a small fake-device mesh and compare against the exact
-local engine. Invoked in a subprocess so the fake-device XLA flag never leaks
-into the parent (smoke tests must see 1 device).
+shard_map executor on a small fake-device CPU mesh and compare against the
+exact oracle, row counts included.  Run it as its own process: the fake-device XLA flag is set
+before jax is imported, so it never leaks into a parent (smoke tests must
+see 1 device).
 
 Usage: python -m repro.launch.dist_selftest [n_dev_data] [n_dev_model]
 """
 import os
 import sys
+from collections import Counter
 
 _d = int(sys.argv[1]) if len(sys.argv) > 1 else 4
 _m = int(sys.argv[2]) if len(sys.argv) > 2 else 2
@@ -16,7 +18,6 @@ os.environ["XLA_FLAGS"] = (
 )
 
 import jax  # noqa: E402
-import numpy as np  # noqa: E402
 
 
 def main() -> int:
@@ -24,11 +25,15 @@ def main() -> int:
     from repro.core.planner import OdysseyOptimizer
     from repro.engine.distributed import DistributedEngine
     from repro.engine.local import LocalEngine, naive_evaluate
-    from repro.rdf.dataset import Federation
-    from repro.rdf.generator import fedbench_like_spec, generate_federation, generate_workload
+    from repro.rdf.generator import (
+        FederationSpec,
+        LinkSpec,
+        SourceSpec,
+        generate_federation,
+        generate_workload,
+    )
 
-    from repro.rdf.generator import FederationSpec, LinkSpec, SourceSpec
-
+    # four sources on every mesh: a (2, m) mesh holds two per data shard
     spec = FederationSpec(sources=[
         SourceSpec("A", n_entities=160, n_templates=6, n_local_preds=10),
         SourceSpec("B", n_entities=120, n_templates=5, n_local_preds=8,
@@ -37,7 +42,7 @@ def main() -> int:
                    links=[LinkSpec("c:ref", "B", 0.4), LinkSpec("c:self", "C", 0.3)]),
         SourceSpec("D", n_entities=80, n_templates=4, n_local_preds=8,
                    links=[LinkSpec("owl:sameAs", "A", 0.4)]),
-    ][:_d], seed=21)
+    ], seed=21)
     fed, gt = generate_federation(spec)
     stats = build_federated_stats(fed)
     queries = generate_workload(fed, gt, n_star=6, n_hybrid=4, n_path=2, seed=9)
@@ -45,40 +50,33 @@ def main() -> int:
     opt = OdysseyOptimizer(stats)
     local = LocalEngine(fed)
     aware = os.environ.get("REPRO_PARTITION_AWARE", "1") == "1"
-    dist = DistributedEngine(fed, mesh, cap=4096, partition_aware=aware)
+    # a small first capacity, so the larger answers take the overflow retry
+    dist = DistributedEngine(fed, mesh, cap=256, partition_aware=aware)
 
-    n_ok = 0
-    n_run = 0
+    n_ok = n_run = retries = 0
     for q in queries:
         plan = opt.optimize(q)
         if plan.fallback:
-            continue
-        res_l = local.execute(plan)
-        rel_l, m_l = res_l.rows, res_l.metrics
+            continue  # variable predicates: the SPMD engine refuses them
         proj = q.effective_projection()
-        nl = len(next(iter(rel_l.values()))) if rel_l else 0
-        want = set(zip(*[rel_l[v].tolist() for v in proj])) if nl else set()
-        # gold standard too
-        gold = naive_evaluate(fed, q)
-        try:
-            res_d = dist.execute(plan)
-            rel_d, m_d = res_d.rows, res_d.metrics
-        except AssertionError:
-            continue  # plan shape unsupported (e.g. cartesian) — skip
-        nd = len(next(iter(rel_d.values()))) if rel_d else 0
-        got = set(zip(*[rel_d[v].tolist() for v in proj])) if nd else set()
+        rel_l = local.execute(plan).rows
+        want = Counter(zip(*[rel_l[v].tolist() for v in proj]))
+        gold = naive_evaluate(fed, q, bag=True)
+        res_d = dist.execute(plan)
+        rel_d = res_d.rows
+        retries += res_d.metrics.capacity_retries
+        got = Counter(zip(*[rel_d[v].tolist() for v in proj]))
         n_run += 1
-        if m_d.overflowed:
-            print(f"OVERFLOW {q.name}")
-            continue
-        if got == gold and (not q.distinct or got == want):
+        if got == gold == want:
             n_ok += 1
         else:
-            print(f"FAIL {q.name}: dist={len(got)} gold={len(gold)}")
-            a = sorted(gold - got)[:3]
-            b = sorted(got - gold)[:3]
+            print(f"FAIL {q.name}: dist={got.total()} local={want.total()} "
+                  f"gold={gold.total()} rows")
+            a = sorted((gold - got).items())[:3]
+            b = sorted((got - gold).items())[:3]
             print("  missing:", a, " extra:", b)
-    print(f"dist_selftest: {n_ok}/{n_run} queries OK on mesh ({_d},{_m})")
+    print(f"dist_selftest: {n_ok}/{n_run} queries OK on mesh ({_d},{_m}), "
+          f"{retries} capacity retries")
     return 0 if (n_run > 0 and n_ok == n_run) else 1
 
 

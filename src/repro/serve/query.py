@@ -63,6 +63,7 @@ class QueryRequest:
     plan: PhysicalPlan | None = None
     rows: dict | None = None
     metrics: ExecutionMetrics | None = None
+    fallback: str | None = None        # engine substitution, if any (ExecutionResult.fallback)
     done: bool = False
     cached: bool = False               # plan served from the plan cache
     stats_epoch: int = 0               # epoch the plan was emitted under
@@ -261,7 +262,7 @@ class QueryServeEngine:
         t0 = self._clock()
         for req in batch:
             res = self.engine.execute(req.plan)
-            req.rows, req.metrics = res.rows, res.metrics
+            req.rows, req.metrics, req.fallback = res.rows, res.metrics, res.fallback
             if self.feedback is not None:
                 self.feedback.observe_result(res)   # thread-safe
             req.done = True

@@ -381,7 +381,7 @@ def test_optional_answers_carry_undef(tiny_fed, tiny_stats):
 
 
 def test_spmd_engine_rejects_algebra_plans(tiny_fed, tiny_stats):
-    from repro.engine.distributed import DistMetrics, DistributedEngine
+    from repro.engine.distributed import DistributedEngine, _Run
     from repro.rdf.generator import generate_extended_workload
 
     fed, gt = tiny_fed
@@ -392,4 +392,4 @@ def test_spmd_engine_rejects_algebra_plans(tiny_fed, tiny_stats):
     # bare instance is enough -- no fake-device subprocess needed here
     eng = object.__new__(DistributedEngine)
     with pytest.raises(NotImplementedError, match="conjunctive"):
-        eng._eval_node(plan.root, DistMetrics())
+        eng._eval_node(plan.root, _Run(cap=2048))

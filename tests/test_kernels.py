@@ -129,8 +129,6 @@ def test_dp_layer_sweep(B, R, C):
     plus the per-column first-strict-minimum — exact equality, including on
     injected cost ties (the DP's tie-breaking contract) and all-invalid
     columns."""
-    from jax.experimental import enable_x64
-
     from repro.kernels.dp_layer import dp_layer
 
     rng = np.random.default_rng(B * 1000 + R + C)
@@ -149,7 +147,7 @@ def test_dp_layer_sweep(B, R, C):
     params = (1.0, 1.0, 5.0, 20)
     got = dp_layer(cost_a, cost_b, card_a, n_src_b, src_w_b, bindable, valid,
                    card_s, params)
-    with enable_x64():
+    with jax.enable_x64(True):
         want = ref.dp_layer_ref(
             jnp.asarray(cost_a), jnp.asarray(cost_b), jnp.asarray(card_a),
             jnp.asarray(n_src_b), jnp.asarray(src_w_b), jnp.asarray(bindable),
@@ -166,8 +164,6 @@ def test_cost_jnp_twins_bitwise_equal_numpy_forms():
     bit for bit under x64 — the contract the on-device sweep's bit-identical
     plans rest on (``hash_join_cost_jnp`` runs inside the kernel; the others
     are pinned here so they cannot silently drift)."""
-    from jax.experimental import enable_x64
-
     from repro.core.cost import CostModel
 
     rng = np.random.default_rng(23)
@@ -178,7 +174,7 @@ def test_cost_jnp_twins_bitwise_equal_numpy_forms():
     n_src = rng.integers(1, 6, 257).astype(np.float64)
     src_w = rng.uniform(0.25, 4.0, 257)
     bindable = rng.random(257) < 0.5
-    with enable_x64():
+    with jax.enable_x64(True):
         pairs = [
             (cm.leaf_cost_v(card, n_src, src_w),
              cm.leaf_cost_jnp(jnp.asarray(card), jnp.asarray(n_src),
@@ -216,8 +212,6 @@ def test_cost_params_form_bitwise_equal_numpy():
     reproduce ``join_candidates_v`` bit for bit under x64, including the
     in-place ``iw * card_out`` hash term — the resident sweep's plans rest
     on it."""
-    from jax.experimental import enable_x64
-
     from repro.core.cost import CostModel
 
     rng = np.random.default_rng(31)
@@ -233,7 +227,7 @@ def test_cost_params_form_bitwise_equal_numpy():
     hj = cm.hash_join_cost_v(card_out)
     want_c, want_b = cm.join_candidates_v(cost_a, cost_b, card_out, hj,
                                           card_a, n_src, src_w, bindable)
-    with enable_x64():
+    with jax.enable_x64(True):
         params = jnp.asarray([cm.intermediate_weight, cm.transfer_weight,
                               cm.request_cost, cm.bind_batch], jnp.float64)
         got_c, got_b = CostModel.join_candidates_params_jnp(
