@@ -201,8 +201,7 @@ def main(argv=None) -> int:
           "queries without DISTINCT); "
           f"{sum(m.capacity_retries for m in cold)} capacity retries (final "
           f"capacities {[m.capacity for m in cold]}); shipped tuples "
-          f"{sum(m.transferred_tuples for m in cold)}, collective bytes "
-          f"{sum(m.collective_bytes for m in cold)}")
+          f"{sum(m.transferred_tuples for m in cold)}")
     print(f"compiles: {counter.compiles} backend compiles, "
           f"{counter.cache_hits} persistent-cache hits")
     print(json.dumps({"ok": True, "device": {

@@ -1,4 +1,4 @@
 from repro.common.hashing import fnv1a64, splitmix64
-from repro.common.util import Timer, stable_unique
+from repro.common.util import stable_unique
 
-__all__ = ["fnv1a64", "splitmix64", "Timer", "stable_unique"]
+__all__ = ["fnv1a64", "splitmix64", "stable_unique"]

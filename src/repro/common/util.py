@@ -1,30 +1,6 @@
 from __future__ import annotations
 
-import time
-from dataclasses import dataclass, field
-
 import numpy as np
-
-
-@dataclass
-class Timer:
-    """Accumulating wall-clock timer: ``with timer: ...``; ``timer.total_s``."""
-
-    total_s: float = 0.0
-    count: int = 0
-    _t0: float = field(default=0.0, repr=False)
-
-    def __enter__(self) -> "Timer":
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc) -> None:
-        self.total_s += time.perf_counter() - self._t0
-        self.count += 1
-
-    @property
-    def mean_ms(self) -> float:
-        return 1e3 * self.total_s / max(1, self.count)
 
 
 def stable_unique(values: np.ndarray) -> tuple[np.ndarray, np.ndarray]:

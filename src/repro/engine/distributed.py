@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import time
 import warnings
 from dataclasses import dataclass, field
 
@@ -39,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.common.trace import span
 from repro.core.planner import (
     JoinPlanNode,
     PhysicalPlan,
@@ -107,9 +109,9 @@ class DistRelation:
 @dataclass
 class DistMetrics:
     transferred_tuples: int = 0
-    collective_bytes: int = 0
     capacity: int = 0            # rows per shard and operator of the answering run
     capacity_retries: int = 0    # doublings paid before the answering run
+    retry_ms: float = 0.0        # host wall time of the overflowed attempts
 
 
 @dataclass
@@ -120,7 +122,7 @@ class _Run:
 
     cap: int
     overflow: list = field(default_factory=list)
-    shipped: list = field(default_factory=list)   # (device count, bytes per row)
+    shipped: list = field(default_factory=list)   # device counts of shipped rows
 
 
 def _enc_pattern(tp: TriplePattern) -> list[int]:
@@ -162,6 +164,7 @@ def _star_program(mesh: Mesh, n_pat: int, cap: int):
         return (rel.reshape(1, 1, rows, n_pat + 1),
                 valid.reshape(1, 1, rows), jnp.any(ovf).reshape(1, 1))
 
+    per_shard.__name__ = f"fed_star{n_pat}"    # the program's name in a trace
     return jax.jit(jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(_SHARDED, _SHARDED, P("data"), P()),
@@ -230,6 +233,7 @@ def _exchange_program(mesh: Mesh, cap: int, right_partitioned: bool):
         return (out[None, None], ovalid[None, None],
                 ovf_any[None, None], shipped_total[None, None])
 
+    per_shard.__name__ = "fed_exchange_part" if right_partitioned else "fed_exchange"
     return jax.jit(jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(_SHARDED, _SHARDED, _SHARDED, _SHARDED, P(), P()),
@@ -248,6 +252,7 @@ def _collect_program(mesh: Mesh):
         val_g = jax.lax.all_gather(val_g, "data", tiled=True)
         return rel_g[None, None], val_g[None, None]
 
+    per_shard.__name__ = "fed_collect"
     return jax.jit(jax.shard_map(
         per_shard, mesh=mesh, in_specs=(_SHARDED, _SHARDED),
         out_specs=(P(None, None), P(None, None)), check_vma=False))
@@ -422,7 +427,7 @@ class DistributedEngine:
             left.data, left.valid, right.data, right.valid,
             jnp.int32(lkey), jnp.int32(rkey))
         run.overflow.append(ovf)
-        run.shipped.append((shipped, 4 * (len(left.columns) + len(right.columns))))
+        run.shipped.append(shipped)
         return DistRelation(rel, valid, left.columns + right.columns,
                             partitioned_by=jv)
 
@@ -431,11 +436,15 @@ class DistributedEngine:
         overflowed, else the collected relation and the run."""
         run = _Run(cap)
         rel = self._eval_node(plan.root, run)
-        if any(np.any(f) for f in jax.device_get(run.overflow)):
+        with span("engine.readback"):
+            overflowed = any(np.any(f) for f in jax.device_get(run.overflow))
+        if overflowed:
             return None
-        collect = self._program(("collect",), lambda: _collect_program(self.mesh))
-        data, valid = collect(rel.data, rel.valid)
-        return rel, np.asarray(data), np.asarray(valid), run
+        with span("engine.collect"):
+            collect = self._program(("collect",), lambda: _collect_program(self.mesh))
+            data, valid = collect(rel.data, rel.valid)
+            data, valid = np.asarray(data), np.asarray(valid)
+        return rel, data, valid, run
 
     def execute(self, plan: PhysicalPlan) -> ExecutionResult:
         if _has_algebra_nodes(plan.root):
@@ -456,8 +465,17 @@ class DistributedEngine:
         # every pattern contributes at most its subject and object columns
         bound = self.max_cap(2 * len(plan.query.patterns))
         key = query_signature(plan.query)[0]
-        cap, retries = min(self._caps.get(key, self.cap), bound), 0
-        while (got := self._attempt(plan, cap)) is None:
+        cap, retries, retry_ms = min(self._caps.get(key, self.cap), bound), 0, 0.0
+        while True:
+            t0 = time.perf_counter()
+            with span("engine.attempt", cap=cap) as attempt:
+                got = self._attempt(plan, cap)
+                attempt.set_metadata(overflowed=int(got is None))
+            if got is not None:
+                break
+            # an overflowed attempt ends at its overflow readback, so its
+            # device time is in here
+            retry_ms += (time.perf_counter() - t0) * 1e3
             if 2 * cap > bound:
                 raise CapacityExceededError(
                     f"plan for {plan.query.name or 'query'} overflows at "
@@ -466,8 +484,18 @@ class DistributedEngine:
             cap *= 2
             retries += 1
         self._caps[key] = cap
-        rel, data, valid, run = got
+        with span("engine.assemble"):
+            out, n_shipped = self._assemble(plan, *got)
+        metrics = DistMetrics(transferred_tuples=n_shipped, capacity=cap,
+                              capacity_retries=retries, retry_ms=retry_ms)
+        return ExecutionResult(rows=out, metrics=metrics, plan=plan,
+                               stats_epoch=plan.stats_epoch)
 
+    @staticmethod
+    def _assemble(plan: PhysicalPlan, rel: DistRelation, data: np.ndarray,
+                  valid: np.ndarray, run: _Run) -> tuple[dict, int]:
+        """The answer's projected rows from the collected relation, and the
+        rows the run shipped."""
         rows = data.reshape(-1, len(rel.columns))[valid.reshape(-1)]
         first: dict[str, int] = {}
         for j, c in enumerate(rel.columns):
@@ -483,15 +511,8 @@ class DistributedEngine:
             stacked = np.stack([out[v] for v in proj], axis=1)
             _, idx = np.unique(stacked, axis=0, return_index=True)
             out = {v: out[v][np.sort(idx)] for v in proj}
-
-        shipped = jax.device_get([s for s, _ in run.shipped])
-        n_ship = [int(np.ravel(s)[0]) for s in shipped]
-        metrics = DistMetrics(
-            transferred_tuples=sum(n_ship),
-            collective_bytes=sum(n * b for n, (_, b) in zip(n_ship, run.shipped)),
-            capacity=cap, capacity_retries=retries)
-        return ExecutionResult(rows=out, metrics=metrics, plan=plan,
-                               stats_epoch=plan.stats_epoch)
+        n_shipped = sum(int(np.ravel(s)[0]) for s in jax.device_get(run.shipped))
+        return out, n_shipped
 
 
 # ---------------------------------------------------------------------------

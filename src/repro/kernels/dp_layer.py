@@ -270,8 +270,8 @@ def dp_layer(cost_a, cost_b, card_a, n_src_b, src_w_b, bindable, valid,
 
 def _build_sweep_program():
     @jax.jit
-    def sweep(params, pair_a, pair_b, pair_seg, layer_cols,
-              card, excl_cost, excl_w, cost0, n_src0, src_w0):
+    def dp_sweep(params, pair_a, pair_b, pair_seg, layer_cols,
+                 card, excl_cost, excl_w, cost0, n_src0, src_w0):
         """One ``lax.scan`` over the padded layer schedule.
 
         ``pair_a``/``pair_b``/``pair_seg`` are ``(L, P)`` int32: per layer,
@@ -372,7 +372,7 @@ def _build_sweep_program():
             (pair_a, pair_b, pair_seg, layer_cols))
         return cost, strat, split
 
-    return sweep
+    return dp_sweep
 
 
 def dp_sweep_resident(params: tuple, pair_a, pair_b, pair_seg, layer_cols,

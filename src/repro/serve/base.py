@@ -54,6 +54,8 @@ class ServeStats:
     n_stats_refreshes: int = 0         # feedback-triggered refresh_source calls
     plan_ms: float = 0.0
     exec_ms: float = 0.0
+    admission_wait_ms: float = 0.0     # served requests' t_flushed - t_submit
+    retry_ms: float = 0.0              # served requests' overflowed attempts
 
 
 @runtime_checkable

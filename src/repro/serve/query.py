@@ -41,6 +41,7 @@ import time
 from collections import deque
 from dataclasses import dataclass
 
+from repro.common.trace import span
 from repro.core.batch_planner import plan_affinity
 from repro.core.cost import CostModel
 from repro.core.federation import FederatedStats
@@ -72,6 +73,7 @@ class QueryRequest:
     affinity_tier: str | None = None   # deepest tier shared with its group
     plan_ms: float = 0.0               # this request's own planning cost
     t_submit: float = 0.0
+    t_flushed: float = 0.0             # left admission in a batch
     t_planned: float = 0.0
     t_done: float = 0.0
 
@@ -179,14 +181,27 @@ class QueryServeEngine:
                 "the background planner thread died; the engine cannot make "
                 "progress (original exception chained)") from err
 
-    def _note_flush(self, reason: str) -> None:
-        """Stats for one flushed batch — called with the lock held."""
+    def _admit(self, force: bool) -> "tuple[list[QueryRequest], str] | None":
+        """The next batch out of admission, stamped and counted, or ``None``
+        when none is ripe — called with the lock held."""
+        if not len(self._admission):
+            return None
+        with span("serve.admit") as admit:
+            now = self._clock()
+            got = self._admission.next_batch(now, force=force)
+            if got is None:
+                return None
+            batch, reason = got
+            admit.set_metadata(n=len(batch), reason=reason)
+        for req in batch:
+            req.t_flushed = now
         if reason == "full":
             self.serve_stats.n_full_flushes += 1
         elif reason == "deadline":
             self.serve_stats.n_deadline_flushes += 1
         else:
             self.serve_stats.n_forced_flushes += 1
+        return got
 
     # -- admission -----------------------------------------------------------
     def submit(self, query: BGPQuery,
@@ -232,9 +247,10 @@ class QueryServeEngine:
             if applied:
                 with self._cond:
                     self.serve_stats.n_stats_refreshes += len(applied)
-        t0 = self._clock()
-        plans = self.optimizer.optimize_batch([r.query for r in batch])
-        t1 = self._clock()
+        with span("serve.plan", n=len(batch)):
+            t0 = self._clock()
+            plans = self.optimizer.optimize_batch([r.query for r in batch])
+            t1 = self._clock()
         report = self.optimizer.last_batch_report
         for req, plan in zip(batch, plans):
             req.plan = plan
@@ -259,16 +275,23 @@ class QueryServeEngine:
     def _execute_batch(self, batch: "list[QueryRequest]") -> None:
         """Execute one planned batch in the caller's thread; completions
         land on ``finished`` and the unpolled buffer."""
-        t0 = self._clock()
-        for req in batch:
-            res = self.engine.execute(req.plan)
-            req.rows, req.metrics, req.fallback = res.rows, res.metrics, res.fallback
-            if self.feedback is not None:
-                self.feedback.observe_result(res)   # thread-safe
-            req.done = True
-            req.t_done = self._clock()
+        with span("serve.execute", n=len(batch)):
+            t0 = self._clock()
+            for req in batch:
+                with span("serve.run", qid=req.qid):
+                    res = self.engine.execute(req.plan)
+                req.rows, req.metrics, req.fallback = res.rows, res.metrics, res.fallback
+                if self.feedback is not None:
+                    self.feedback.observe_result(res)   # thread-safe
+                req.done = True
+                req.t_done = self._clock()
+            t1 = self._clock()
         with self._cond:
-            self.serve_stats.exec_ms += (self._clock() - t0) * 1e3
+            self.serve_stats.exec_ms += (t1 - t0) * 1e3
+            self.serve_stats.admission_wait_ms += sum(
+                r.t_flushed - r.t_submit for r in batch) * 1e3
+            self.serve_stats.retry_ms += sum(
+                getattr(r.metrics, "retry_ms", 0.0) for r in batch)
             self.serve_stats.n_served += len(batch)
             self.serve_stats.n_steps += 1
             self.finished.extend(batch)
@@ -293,9 +316,7 @@ class QueryServeEngine:
                 "use poll()/drain()")
         self._raise_worker_error()
         with self._cond:
-            got = self._admission.next_batch(self._clock(), force=True)
-            if got is not None:
-                self._note_flush(got[1])
+            got = self._admit(force=True)
         if got is not None:
             batch, _ = got
             self._plan_batch(batch)
@@ -321,9 +342,7 @@ class QueryServeEngine:
         else:
             while True:
                 with self._cond:
-                    got = self._admission.next_batch(self._clock(), force=False)
-                    if got is not None:
-                        self._note_flush(got[1])
+                    got = self._admit(force=False)
                 if got is None:
                     break
                 batch, _ = got

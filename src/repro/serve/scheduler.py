@@ -206,13 +206,10 @@ class PlannerWorker(threading.Thread):
                     while got is None:
                         if eng._stopping and not len(eng._admission):
                             return
-                        now = eng._clock()
-                        force = eng._force_flush or eng._stopping
-                        got = eng._admission.next_batch(now, force=force)
+                        got = eng._admit(eng._force_flush or eng._stopping)
                         if got is None:
                             eng._cond.wait(self._WAIT_S)
-                    batch, reason = got
-                    eng._note_flush(reason)
+                    batch, _ = got
                     eng._cond.notify_all()     # submit() may unblock now
                 eng._plan_batch(batch)         # outside the lock: the overlap
                 with eng._cond:

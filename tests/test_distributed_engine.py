@@ -155,3 +155,84 @@ def test_table_cap_too_small_is_refused(tiny_fed):
     assert np.all(np.asarray(
         DistributedEngine(fed, _one_device_mesh()).trow).sum(axis=(1, 2))
         == [len(s.table) for s in fed.sources])
+
+
+def test_retry_ms_times_exactly_the_overflowed_attempts(one_device):
+    """``retry_ms`` is the host time of the attempts that overflowed: above
+    0 when the plan was run again, 0.0 when the first attempt answered."""
+    fed, queries, opt = one_device
+    eng = DistributedEngine(fed, _one_device_mesh(), cap=8)
+    q = max(queries, key=lambda q: len(naive_evaluate(fed, q)))
+    retried = eng.execute(opt.optimize(q)).metrics
+    assert retried.capacity_retries >= 1 and retried.retry_ms > 0.0
+    again = eng.execute(opt.optimize(q)).metrics      # starts at the grown capacity
+    assert again.capacity_retries == 0 and again.retry_ms == 0.0
+
+
+def _lowered_star(mesh):
+    from repro.engine.distributed import _star_program
+
+    sds = jax.ShapeDtypeStruct
+    return _star_program(mesh, 3, 64).lower(
+        sds((9, 1, 256, 3), np.int32), sds((9, 1, 256), np.bool_), sds((9,), np.bool_),
+        sds((3, 3), np.int32))
+
+
+def _lowered_exchange(mesh, right_partitioned):
+    from repro.engine.distributed import _exchange_program
+
+    sds = jax.ShapeDtypeStruct
+    rel, valid = sds((1, 1, 64, 3), np.int32), sds((1, 1, 64), np.bool_)
+    return _exchange_program(mesh, 64, right_partitioned).lower(
+        rel, valid, rel, valid, sds((), np.int32), sds((), np.int32))
+
+
+def _lowered_collect(mesh):
+    from repro.engine.distributed import _collect_program
+
+    sds = jax.ShapeDtypeStruct
+    return _collect_program(mesh).lower(sds((1, 1, 64, 3), np.int32),
+                                        sds((1, 1, 64), np.bool_))
+
+
+def _lowered_dp_sweep(mesh):
+    from repro.kernels.dp_layer import _build_sweep_program
+
+    sds = jax.ShapeDtypeStruct
+    B, size, L, P, C = 2, 16, 3, 8, 4
+    idx = [sds((L, P), np.int32)] * 3 + [sds((L, C), np.int32)]
+    with jax.enable_x64(True):
+        plane = sds((B, size), np.float64)
+        return _build_sweep_program().lower(sds((4,), np.float64), *idx,
+                                            *[plane] * 6)
+
+
+@pytest.mark.parametrize("name, lower", [
+    ("jit_fed_star3", _lowered_star),
+    ("jit_fed_exchange", lambda mesh: _lowered_exchange(mesh, False)),
+    ("jit_fed_exchange_part", lambda mesh: _lowered_exchange(mesh, True)),
+    ("jit_fed_collect", _lowered_collect),
+    ("jit_dp_sweep", _lowered_dp_sweep),
+])
+def test_device_programs_have_stable_names(name, lower):
+    """Each device program's module carries the name a trace shows for it,
+    whatever its capacity or shapes."""
+    text = lower(_one_device_mesh()).as_text()
+    assert f"module @{name} " in text
+
+
+def test_spans_without_a_profiler_record_nothing(one_device, tmp_path):
+    """Served with no profiler session, the executor's spans raise nothing
+    and leave nothing for a later session to find."""
+    from jax.profiler import ProfileData, TraceAnnotation
+
+    fed, queries, opt = one_device
+    eng = DistributedEngine(fed, _one_device_mesh(), cap=8)
+    assert not TraceAnnotation.is_enabled()
+    res = eng.execute(opt.optimize(queries[0]))
+    assert _answers(res, queries[0]) == naive_evaluate(fed, queries[0], bag=True)
+    jax.profiler.start_trace(str(tmp_path))
+    jax.profiler.stop_trace()
+    pd = ProfileData.from_file(str(next(tmp_path.rglob("*.xplane.pb"))))
+    names = {e.name for plane in pd.planes for line in plane.lines for e in line.events}
+    assert not any(n.startswith("repro.") for n in names)

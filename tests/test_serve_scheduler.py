@@ -513,3 +513,23 @@ def test_execution_result_fields_and_shim(tiny_fed, tiny_stats, tiny_workload):
     assert rows is res.rows and metrics is res.metrics
     with pytest.raises(Exception):
         res.rows = {}                              # frozen
+
+
+@pytest.mark.parametrize("pipeline", [False, True])
+def test_request_stamps_follow_the_request(tiny_fed, tiny_stats, tiny_workload,
+                                           pipeline):
+    """Every request is stamped in the order it moves: submitted, flushed out
+    of admission, planned, done; the admission wait of the served requests is
+    summed on ``ServeStats``."""
+    fed, _ = tiny_fed
+    with QueryServeEngine(fed, tiny_stats, max_batch=4, pipeline=pipeline,
+                          default_slo_ms=2.0) as eng:
+        reqs = [eng.submit(q) for q in tiny_workload]
+        done = eng.drain()
+        assert sorted(r.qid for r in done) == [r.qid for r in reqs]
+        for r in done:
+            assert 0.0 < r.t_submit <= r.t_flushed <= r.t_planned <= r.t_done, r.qid
+        assert eng.serve_stats.admission_wait_ms == pytest.approx(
+            1e3 * sum(r.t_flushed - r.t_submit for r in done))
+        # the local engine runs no capacity retries
+        assert eng.serve_stats.retry_ms == 0.0
