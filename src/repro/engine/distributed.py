@@ -171,6 +171,14 @@ def _star_program(mesh: Mesh, n_pat: int, cap: int):
         out_specs=(_SHARDED, _SHARDED, _SHARDED), check_vma=False))
 
 
+def _bucket_rank(dest: jax.Array):
+    """Rows ordered by destination bucket (stably), the ordered buckets, and
+    each ordered row's rank within its bucket."""
+    pos = jnp.arange(dest.shape[0], dtype=jnp.int32)
+    sorted_dest, order = jax.lax.sort((dest, pos), num_keys=1, is_stable=True)
+    return order, sorted_dest, pos - ops.run_start(sorted_dest, pos)
+
+
 def _exchange_program(mesh: Mesh, cap: int, right_partitioned: bool):
     """Repartition rows over the model axis by hash of a key column, then
     merge-join against a local build side: the distributed hash join.
@@ -187,14 +195,11 @@ def _exchange_program(mesh: Mesh, cap: int, right_partitioned: bool):
         lvalid = lvalid[0, 0]
         rrel = rrel[0, 0]
         rvalid = rvalid[0, 0]
-        n_left, ncols = lrel.shape
+        ncols = lrel.shape[1]
         # --- exchange left rows by key % m over the model axis -------------
         keyv = jnp.take(lrel, lkey, axis=1)
         dest = jnp.where(lvalid, keyv % m, m)  # m = drop bucket
-        order = jnp.argsort(dest, stable=True)
-        sorted_dest = dest[order]
-        idx_in_dest = jnp.arange(n_left) - jnp.searchsorted(
-            sorted_dest, sorted_dest, side="left")
+        order, sorted_dest, idx_in_dest = _bucket_rank(dest)
         ovf = jnp.max(jnp.where(sorted_dest < m, idx_in_dest, 0)) >= bucket_cap
         slot = jnp.clip(idx_in_dest, 0, bucket_cap - 1)
         row_ok = (sorted_dest < m) & (idx_in_dest < bucket_cap)

@@ -9,7 +9,11 @@ Rows beyond the live count are zeroed and invalid. Overflow flags propagate to
 the host, which runs the plan again with a doubled capacity
 (``DistributedEngine.execute``) and never keeps a truncated result.
 Compaction uses stable sorts instead of gathers-with-dynamic-shapes; joins use
-the counts/offsets construction that ``kernels/join_count`` accelerates.
+the counts/offsets construction that ``kernels/join_count`` accelerates, and
+find each left row's matches and each output row's left row with sorts,
+running sums and running maxima (``match_runs``, ``slot_owner``), never with
+a sequential binary search: on the TPU every step of one is a dependent
+gather over all rows.
 """
 from __future__ import annotations
 
@@ -17,6 +21,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 
 def compact(mask: jax.Array, cap: int) -> tuple[jax.Array, jax.Array, jax.Array]:
@@ -61,41 +66,87 @@ def semi_bind(rel: jax.Array, valid: jax.Array, keys: jax.Array, kvalid: jax.Arr
     return jnp.where(v[:, None], rel[idx], 0), v, ovf
 
 
+def run_start(keys: jax.Array, values: jax.Array) -> jax.Array:
+    """``values`` at the first position of each element's run of equal
+    ``keys``, for sorted ``keys`` and non-negative, non-decreasing
+    ``values``: a running maximum over the runs' first positions."""
+    begins = jnp.concatenate([jnp.ones(1, bool), keys[1:] != keys[:-1]])
+    return lax.cummax(jnp.where(begins, values, 0))
+
+
+def match_runs(lkey: jax.Array, rkey: jax.Array):
+    """Where each left key's equal right keys are, by sorting both sides'
+    keys together.
+
+    One stable sort of the right keys followed by the left keys puts, at an
+    equal key, every right row before every left row, each side in its own
+    order; a left row matches the right rows from its key's first position
+    up to itself.  A second sort, by original position, brings the counts
+    back to the left's order.  Returns ``(count, first, rows)``: left row
+    ``i`` matches ``count[i]`` right rows, and the ``r``-th of them in the
+    right's order is right row ``rows[first[i] + r]``."""
+    R = rkey.shape[0]
+    pos = jnp.arange(R + lkey.shape[0], dtype=jnp.int32)
+    key, rows = lax.sort((jnp.concatenate([rkey, lkey]), pos), num_keys=1,
+                         is_stable=True)
+    is_right = (rows < R).astype(jnp.int32)
+    n_right = jnp.cumsum(is_right) - is_right        # right rows before each
+    first = run_start(key, pos)
+    count = n_right - run_start(key, n_right)
+    _, count, first = lax.sort((rows, count, first), num_keys=1)
+    return count[R:], first[R:], rows
+
+
+def slot_owner(counts: jax.Array, offsets: jax.Array, cap: int) -> jax.Array:
+    """The row that owns each of ``cap`` output slots when row ``i`` fills
+    the ``counts[i]`` slots that end at ``offsets = cumsum(counts)``; right
+    for every slot below ``offsets[-1]``.  Each row that fills a slot marks
+    its first one (no two rows mark the same slot), and a running maximum
+    carries the mark over the row's other slots."""
+    row = jnp.arange(counts.shape[0], dtype=jnp.int32)
+    begin = offsets - counts
+    slot = jnp.where((counts > 0) & (begin < cap), begin, cap + row)
+    marks = jnp.zeros(cap, jnp.int32).at[slot].set(row, mode="drop",
+                                                  unique_indices=True)
+    return lax.cummax(marks)
+
+
+def _expand(left, right, first, rows, counts, offsets, cap):
+    """Output slot ``t``'s left row and the right row of its match (by its
+    rank among its left row's slots), for every ``t`` below
+    ``offsets[-1]``; rows past it are clipped into range."""
+    li = slot_owner(counts, offsets, cap)
+    at = (first - (offsets - counts))[li] + jnp.arange(cap)
+    ri = rows[jnp.clip(at, 0, rows.shape[0] - 1)]
+    return li, jnp.clip(ri, 0, right.shape[0] - 1)
+
+
 @partial(jax.jit, static_argnames=("cap",))
 def merge_join(left: jax.Array, lvalid: jax.Array, lkey: int,
                right: jax.Array, rvalid: jax.Array, rkey: int,
                cap: int):
     """Inner join on one key column with bounded output.
 
-    Sorts the right side by key, computes per-left-row match counts and
-    offsets, then materializes output row ``t`` by locating its (left row,
-    match rank) via searchsorted on the cumulative counts — no dynamic shapes.
+    Finds each left row's run of equal right keys (``match_runs``), gives
+    left row ``i`` as many output rows as it has matches, in the left's
+    order (offsets by a running sum), and materializes output row ``t`` from
+    its left row (``slot_owner``) and its rank among that row's matches, in
+    the right's order — no dynamic shapes, no binary search.
     Output columns: left cols ++ right cols (join key duplicated).
     """
-    L = left.shape[0]
-    # sort right by key; invalid rows to the end with key = INT32_MAX
+    # invalid rows take the largest keys, right ones above left ones, so an
+    # invalid left row matches no right row
     BIG = jnp.int32(2**31 - 1)
     rk = jnp.where(rvalid, right[:, rkey], BIG)
-    order = jnp.argsort(rk, stable=True)
-    right_s = right[order]
-    rvalid_s = rvalid[order]
-    rk_s = rk[order]
-
     lk = jnp.where(lvalid, left[:, lkey], BIG - 1)
-    start = jnp.searchsorted(rk_s, lk, side="left")
-    end = jnp.searchsorted(rk_s, lk, side="right")
-    counts = jnp.where(lvalid, end - start, 0)
+    count, first, rows = match_runs(lk, rk)
+    counts = jnp.where(lvalid, count, 0)
     offsets = jnp.cumsum(counts)
     total = offsets[-1]
-
-    t = jnp.arange(cap)
-    li = jnp.searchsorted(offsets, t, side="right")
-    li_c = jnp.clip(li, 0, L - 1)
-    prev = jnp.where(li_c > 0, offsets[li_c - 1], 0)
-    rank = t - prev
-    ri = jnp.clip(start[li_c] + rank, 0, right.shape[0] - 1)
-    valid = (t < total) & lvalid[li_c] & rvalid_s[ri]
-    data = jnp.concatenate([left[li_c], right_s[ri]], axis=1)
+    li, ri = _expand(left, right, first, rows, counts, offsets, cap)
+    # a slot below the total belongs to a valid left row
+    valid = (jnp.arange(cap) < total) & rvalid[ri]
+    data = jnp.concatenate([left[li], right[ri]], axis=1)
     data = jnp.where(valid[:, None], data, 0)
     return data, valid, total > cap
 
@@ -133,32 +184,20 @@ def left_merge_join(left: jax.Array, lvalid: jax.Array, lkey: int,
     """OPTIONAL on one key column with bounded output: ``merge_join`` plus
     one pad row per unmatched valid left row, right columns set to UNDEF.
     Output columns: left cols ++ right cols, like ``merge_join``."""
-    L = left.shape[0]
     BIG = jnp.int32(2**31 - 1)
     rk = jnp.where(rvalid, right[:, rkey], BIG)
-    order = jnp.argsort(rk, stable=True)
-    right_s = right[order]
-    rk_s = rk[order]
-
     lk = jnp.where(lvalid, left[:, lkey], BIG - 1)
-    start = jnp.searchsorted(rk_s, lk, side="left")
-    end = jnp.searchsorted(rk_s, lk, side="right")
-    counts = jnp.where(lvalid, end - start, 0)
+    count, first, rows = match_runs(lk, rk)
+    counts = jnp.where(lvalid, count, 0)
     # every valid left row emits max(matches, 1) rows
     outcnt = jnp.where(lvalid, jnp.maximum(counts, 1), 0)
     offsets = jnp.cumsum(outcnt)
     total = offsets[-1]
-
-    t = jnp.arange(cap)
-    li = jnp.searchsorted(offsets, t, side="right")
-    li_c = jnp.clip(li, 0, L - 1)
-    prev = jnp.where(li_c > 0, offsets[li_c - 1], 0)
-    rank = t - prev
-    matched = counts[li_c] > 0
-    ri = jnp.clip(start[li_c] + rank, 0, right.shape[0] - 1)
-    valid = (t < total) & lvalid[li_c]
-    rdata = jnp.where(matched[:, None], right_s[ri], jnp.int32(UNDEF))
-    data = jnp.concatenate([left[li_c], rdata], axis=1)
+    li, ri = _expand(left, right, first, rows, outcnt, offsets, cap)
+    matched = counts[li] > 0
+    valid = jnp.arange(cap) < total
+    rdata = jnp.where(matched[:, None], right[ri], jnp.int32(UNDEF))
+    data = jnp.concatenate([left[li], rdata], axis=1)
     data = jnp.where(valid[:, None], data, 0)
     return data, valid, total > cap
 

@@ -11,6 +11,7 @@ import sys
 from collections import Counter
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -169,13 +170,13 @@ def test_retry_ms_times_exactly_the_overflowed_attempts(one_device):
     assert again.capacity_retries == 0 and again.retry_ms == 0.0
 
 
-def _lowered_star(mesh):
+def _lowered_star(mesh, n_pat=3):
     from repro.engine.distributed import _star_program
 
     sds = jax.ShapeDtypeStruct
-    return _star_program(mesh, 3, 64).lower(
+    return _star_program(mesh, n_pat, 64).lower(
         sds((9, 1, 256, 3), np.int32), sds((9, 1, 256), np.bool_), sds((9,), np.bool_),
-        sds((3, 3), np.int32))
+        sds((n_pat, 3), np.int32))
 
 
 def _lowered_exchange(mesh, right_partitioned):
@@ -185,6 +186,83 @@ def _lowered_exchange(mesh, right_partitioned):
     rel, valid = sds((1, 1, 64, 3), np.int32), sds((1, 1, 64), np.bool_)
     return _exchange_program(mesh, 64, right_partitioned).lower(
         rel, valid, rel, valid, sds((), np.int32), sds((), np.int32))
+
+
+@pytest.mark.parametrize("lower", [
+    *(lambda mesh, n=n: _lowered_star(mesh, n) for n in (1, 2, 3, 4)),
+    lambda mesh: _lowered_exchange(mesh, False),
+    lambda mesh: _lowered_exchange(mesh, True),
+], ids=["star1", "star2", "star3", "star4", "exchange", "exchange_part"])
+def test_executor_programs_hold_no_loop(lower):
+    """No star or exchange program holds a ``while`` loop: a sequential
+    search (``jnp.searchsorted``'s default) is a loop of dependent gathers
+    over every row, which held most of the star programs' device time."""
+    assert "stablehlo.while" not in lower(_one_device_mesh()).as_text()
+
+
+def _searchsorted_bucket_rank(dest):
+    """The exchange's bucket order and rank as they were written with
+    ``jnp.searchsorted``: the oracle."""
+    order = jnp.argsort(dest, stable=True)
+    sorted_dest = dest[order]
+    rank = jnp.arange(dest.shape[0]) - jnp.searchsorted(sorted_dest, sorted_dest,
+                                                       side="left")
+    return order, sorted_dest, rank
+
+
+# (rows, buckets, valid share, key range): invalid rows go to the drop
+# bucket ``buckets``
+_BUCKET_CASES = {
+    "all_dropped": (40, 4, 0.0, 100),
+    "one_bucket": (40, 1, 1.0, 100),
+    "one_key_of_four_buckets": (40, 4, 1.0, 1),
+    "mixed": (200, 4, 0.7, 1000),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BUCKET_CASES))
+def test_bucket_rank_equals_searchsorted(case):
+    from repro.engine.distributed import _bucket_rank
+
+    n, m, share, keys = _BUCKET_CASES[case]
+    rng = np.random.default_rng(n + m)
+    key = rng.integers(0, keys, n).astype(np.int32)
+    dest = jnp.asarray(np.where(rng.random(n) < share, key % m, m).astype(np.int32))
+    for got, want in zip(_bucket_rank(dest), _searchsorted_bucket_rank(dest)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# valid left rows: none (all dropped), exactly the one bucket's capacity,
+# and one more than it
+_EXCHANGE_CASES = {"all_dropped": 0, "one_bucket_full": 16, "bucket_overflows": 17}
+
+
+@pytest.mark.parametrize("right_partitioned", [False, True])
+@pytest.mark.parametrize("case", sorted(_EXCHANGE_CASES))
+def test_exchange_equals_searchsorted_rank(case, right_partitioned, monkeypatch):
+    """On the one-device mesh, the exchange program gives bit for bit the
+    rows, validity, overflow flag and shipped count it gives with its
+    bucket rank found by binary search."""
+    from repro.engine import distributed
+
+    cap, n = 16, 3 * 16        # three local sources' rows, one bucket of cap
+    rng = np.random.default_rng(len(case))
+    lvalid = np.zeros(n, bool)
+    lvalid[rng.permutation(n)[:_EXCHANGE_CASES[case]]] = True
+    left = rng.integers(0, 6, (1, 1, n, 3)).astype(np.int32)
+    right = rng.integers(0, 60, (1, 1, n, 3)).astype(np.int32)  # few matches
+    args = (jnp.asarray(left), jnp.asarray(lvalid.reshape(1, 1, n)), jnp.asarray(right),
+            jnp.asarray(rng.random((1, 1, n)) < 0.8), jnp.int32(1), jnp.int32(0))
+    mesh = _one_device_mesh()
+    got = jax.device_get(distributed._exchange_program(mesh, cap, right_partitioned)(*args))
+    monkeypatch.setattr(distributed, "_bucket_rank", _searchsorted_bucket_rank)
+    want = jax.device_get(distributed._exchange_program(mesh, cap, right_partitioned)(*args))
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+    _, valid, ovf, _ = got
+    assert bool(np.ravel(ovf)[0]) == (case == "bucket_overflows")
+    if case == "one_bucket_full":
+        assert np.asarray(valid).any()
 
 
 def _lowered_collect(mesh):

@@ -152,3 +152,105 @@ def test_compare_mask_two_valued_and_filter_rows():
     got = np.asarray(data)[np.asarray(v)]
     np.testing.assert_array_equal(got, [[5, 3]])
     assert not bool(ovf)
+
+
+# --------------------------------------------------------------------------
+# Search-free joins == the binary-search formulation they replaced
+# --------------------------------------------------------------------------
+
+_BIG = 2**31 - 1
+
+
+def _searchsorted_join(left, lvalid, lkey, right, rvalid, rkey, cap, pad):
+    """The bounded joins as they were written with ``jnp.searchsorted``
+    (inner join, or OPTIONAL when ``pad``): the oracle of row order, row
+    validity and overflow."""
+    L = left.shape[0]
+    rk = jnp.where(rvalid, right[:, rkey], _BIG)
+    order = jnp.argsort(rk, stable=True)
+    right_s, rvalid_s, rk_s = right[order], rvalid[order], rk[order]
+    lk = jnp.where(lvalid, left[:, lkey], _BIG - 1)
+    start = jnp.searchsorted(rk_s, lk, side="left")
+    end = jnp.searchsorted(rk_s, lk, side="right")
+    counts = jnp.where(lvalid, end - start, 0)
+    outcnt = jnp.where(lvalid, jnp.maximum(counts, 1), 0) if pad else counts
+    offsets = jnp.cumsum(outcnt)
+    total = offsets[-1]
+    t = jnp.arange(cap)
+    li = jnp.clip(jnp.searchsorted(offsets, t, side="right"), 0, L - 1)
+    rank = t - jnp.where(li > 0, offsets[li - 1], 0)
+    ri = jnp.clip(start[li] + rank, 0, right.shape[0] - 1)
+    if pad:
+        valid = (t < total) & lvalid[li]
+        rdata = jnp.where((counts[li] > 0)[:, None], right_s[ri], jnp.int32(ops.UNDEF))
+    else:
+        valid = (t < total) & lvalid[li] & rvalid_s[ri]
+        rdata = right_s[ri]
+    data = jnp.concatenate([left[li], rdata], axis=1)
+    return jnp.where(valid[:, None], data, 0), valid, total > cap
+
+
+# (L, R, keys, left valid share, right valid share, cap): keys are drawn
+# from range(keys), or from the three largest int32 values when keys < 0
+_JOIN_CASES = {
+    "duplicates": (64, 64, 8, 0.8, 0.8, 1024),
+    "duplicates_overflow": (64, 64, 4, 0.8, 0.8, 128),
+    "right_13x_left": (32, 13 * 32, 16, 0.7, 0.9, 2048),
+    "right_13x_left_overflow": (32, 13 * 32, 4, 0.9, 0.9, 64),
+    "left_longer": (200, 24, 10, 0.9, 0.6, 512),
+    "left_all_invalid": (48, 48, 6, 0.0, 0.9, 256),
+    "right_all_invalid": (48, 48, 6, 0.9, 0.0, 256),
+    "both_all_invalid": (16, 16, 4, 0.0, 0.0, 32),
+    "one_key_fills_cap": (16, 8, 1, 1.0, 1.0, 128),
+    "one_left_row": (1, 40, 3, 1.0, 0.8, 64),
+    "one_right_row": (40, 1, 3, 0.8, 1.0, 64),
+    "largest_keys": (32, 32, -1, 0.8, 0.8, 512),
+}
+
+
+def _join_inputs(case, seed):
+    L, R, keys, lshare, rshare, cap = _JOIN_CASES[case]
+    rng = np.random.default_rng(seed)
+    if keys < 0:
+        draw = lambda n: rng.choice(np.array([_BIG - 2, _BIG - 1, _BIG], np.int32), (n, 2))
+    else:
+        draw = lambda n: rng.integers(0, keys, (n, 2)).astype(np.int32)
+    left, right = draw(L), draw(R)
+    lvalid, rvalid = rng.random(L) < lshare, rng.random(R) < rshare
+    return (jnp.asarray(left), jnp.asarray(lvalid), 1, jnp.asarray(right),
+            jnp.asarray(rvalid), 0, cap)
+
+
+def _assert_same(got, want):
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("pad", [False, True], ids=["merge_join", "left_merge_join"])
+@pytest.mark.parametrize("case", sorted(_JOIN_CASES))
+def test_join_equals_searchsorted_formulation(case, pad):
+    """Bit for bit the rows, their order, their validity and the overflow
+    flag of the binary-search formulation, on three seeds."""
+    join = ops.left_merge_join if pad else ops.merge_join
+    for seed in range(3):
+        args = _join_inputs(case, seed)
+        _assert_same(join(*args), _searchsorted_join(*args, pad=pad))
+
+
+def test_join_cases_reach_overflow_and_the_exact_fill():
+    """The cases above hold overflowing joins and one whose matches fill
+    the capacity exactly, where ``total > cap`` is false."""
+    flags = {c: bool(ops.merge_join(*_join_inputs(c, 0))[2]) for c in _JOIN_CASES}
+    assert flags["duplicates_overflow"] and flags["right_13x_left_overflow"]
+    assert not flags["duplicates"] and not flags["one_key_fills_cap"]
+    _, valid, _ = ops.merge_join(*_join_inputs("one_key_fills_cap", 0))
+    assert int(np.asarray(valid).sum()) == 128
+
+
+@pytest.mark.parametrize("n, runs", [(1, 1), (12, 1), (12, 12), (50, 7)])
+def test_run_start_marks_each_runs_first_position(n, runs):
+    rng = np.random.default_rng(n + runs)
+    keys = np.sort(rng.integers(0, runs, n)).astype(np.int32)
+    pos = np.arange(n)
+    got = np.asarray(ops.run_start(jnp.asarray(keys), jnp.asarray(pos)))
+    np.testing.assert_array_equal(got, np.searchsorted(keys, keys, side="left"))
