@@ -33,7 +33,7 @@ from pathlib import Path
 
 import numpy as np
 
-from obench import check, datagen, loadgen, tracing
+from obench import attribution, check, datagen, loadgen
 from obench.compiles import CompileCounter
 from obench.reference import Query, Reference
 
@@ -114,7 +114,7 @@ class Window:
     serve: dict               # ServeStats deltas over the measured phase
     capacity_retries: int     # summed over the answers
     compile_requests: int     # in-process compile requests in the measured phase
-    trace: "tracing.Summary | None" = None
+    trace: "attribution.Attribution | None" = None
     # per answer: template, latency s, service s (plan ready to answer), capacity, retries
     answered: list = dataclasses.field(default_factory=list)
     compiled: list = dataclasses.field(default_factory=list)  # programs of those requests
@@ -158,11 +158,24 @@ def traced_engine_class():
 
 
 def to_program_query(q: Query):
-    from repro.query.algebra import BGPQuery, Const, TriplePattern, Var
+    """The program's query for ``q``: ``from_algebra`` of its group tree,
+    or of its BGP, which gives a plain ``BGPQuery``."""
+    from repro.query import algebra as alg
 
-    term = lambda t: Var(t) if isinstance(t, str) else Const(int(t))  # noqa: E731
-    return BGPQuery([TriplePattern(*(term(t) for t in tp)) for tp in q.patterns],
-                    distinct=q.distinct, projection=list(q.projection), name=q.name)
+    term = lambda t: alg.Var(t) if isinstance(t, str) else alg.Const(int(t))  # noqa: E731
+
+    def node(n: tuple):
+        if n[0] == "bgp":
+            return alg.Bgp(tuple(alg.TriplePattern(*(term(t) for t in tp)) for tp in n[1]))
+        if n[0] == "optional":
+            return alg.LeftJoin(node(n[1]), node(n[2]))
+        if n[0] == "union":
+            return alg.Union(tuple(node(m) for m in n[1]))
+        op, lhs, rhs = n[1]
+        return alg.Filter(alg.Comparison(op, term(lhs), term(rhs)), node(n[2]))
+
+    root = q.tree if q.tree is not None else ("bgp", q.patterns)
+    return alg.from_algebra(node(root), q.distinct, list(q.projection), q.name)
 
 
 def _mesh_shape(chips: int) -> tuple[int, int]:
@@ -259,7 +272,7 @@ class Bench:
             files = sorted(trace_dir.rglob("*.xplane.pb"))
             if not files:
                 raise Refusal("the profiler wrote no trace")
-            window.trace = tracing.reduce(*tracing.read(str(files[-1])))
+            window.trace = attribution.reduce(*attribution.read(str(files[-1])))
             shutil.rmtree(trace_dir, ignore_errors=True)
         peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in self.devices)
         # the reference runs on the host, after the window and the memory reading
@@ -501,7 +514,7 @@ def main(argv=None, *, root: "Path | None" = None, t_start: "float | None" = Non
         return 1
     line = result_line(cell, window, checks, device, attempted, failed)
     if window.trace is not None:
-        print(f"bench: device idle seconds by harness span: {window.trace.idle_by_span}",
+        print(f"bench: device idle seconds by innermost span: {window.trace.idle_by_span}",
               file=sys.stderr)
     for text in checks.lines():
         print(text, file=sys.stderr)

@@ -6,6 +6,7 @@ fault fail)."""
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import io
 import json
 import os
@@ -30,6 +31,15 @@ from obench.reference import Query, Reference  # noqa: E402
 
 CELLS = ["fedbench9.bound_open", "largerdf13.heavy_closed"]
 SHRINK = {"fedbench9": 400, "largerdf13": 800}     # entity counts divided by
+# a mix of FedBench's OPTIONAL, UNION and FILTER shapes, inline: no cell sends
+# it yet, and a traffic file could not be edited once a cell did
+ALGEBRA_MIX = {"loop": "open", "rate_qps": 8.0,
+               "templates": {"optional": 3, "union": 3, "filter": 3},
+               "star_patterns": [2, 4], "hybrid_star_patterns": [1, 3],
+               "bind": "first_subject", "popularity_zipf_s": 1.0, "template_seed": 13,
+               "warmup_s": 0}
+ALGEBRA_CELLS = {f"fedbench9.algebra_t{s}": dict(ALGEBRA_MIX, template_seed=s)
+                 for s in (13, 14, 15)}
 
 
 def make_tiny_root(dst: Path) -> Path:
@@ -86,14 +96,14 @@ def _config(root: Path, name: str) -> dict:
 
 
 def _mix(root: Path, name: str) -> dict:
+    if f"fedbench9.{name}" in ALGEBRA_CELLS:
+        return ALGEBRA_CELLS[f"fedbench9.{name}"]
     return json.loads((root / harness.BENCH / "traffic" / f"{name}.json").read_text())
 
 
 # -- the copied generator and the plain reference ----------------------------
 
 def test_generator_copy_equals_program_generator():
-    import dataclasses
-
     from repro.rdf.generator import fedbench_like_spec, generate_federation
 
     spec = fedbench_like_spec(scale=0.3, seed=5)
@@ -113,7 +123,7 @@ def _bag(rows: np.ndarray, distinct: bool) -> Counter:
     return Counter(set(tuples) if distinct else tuples)
 
 
-@pytest.mark.parametrize("cell", CELLS)
+@pytest.mark.parametrize("cell", CELLS + list(ALGEBRA_CELLS))
 def test_reference_equals_naive_evaluate(tiny_root, cell):
     from repro.engine.local import naive_evaluate
 
@@ -124,6 +134,16 @@ def test_reference_equals_naive_evaluate(tiny_root, cell):
     traffic = loadgen.Traffic(data, _mix(tiny_root, mix))
     queries = traffic.warmup() + traffic.schedule(3, 2.0)[1] if traffic.loop == "open" \
         else traffic.warmup()
+    if cell in ALGEBRA_CELLS:
+        # every template drawn, unbound too, and each kind as many times as asked
+        queries += [t.query for t in traffic.templates]
+        kinds = Counter(t.query.tree[0] for t in traffic.templates)
+        assert kinds == {"optional": 3, "union": 3, "filter": 3}
+        for t in traffic.templates:
+            if t.query.tree[0] == "optional":
+                # matched and padded rows: the arm's first variable bound and UNDEF
+                arm = ref.evaluate(dataclasses.replace(t.query, projection=("a0",)))
+                assert 0 < np.count_nonzero(arm == -1) < len(arm), t.query
     nonempty = 0
     for q in queries:
         want = naive_evaluate(fed, harness.to_program_query(q), bag=True)
@@ -336,8 +356,6 @@ def test_refuses_with_the_benchmark_alone(tmp_path):
 def fault_classes():
     """The executor and the server with faults that can be planted
     underneath a run, and the control (capacity retries switched off)."""
-    import dataclasses
-
     import jax.numpy as jnp
 
     from repro.engine import distributed as dist

@@ -1,7 +1,8 @@
 """CPU tests of what the benchmark reads from the program's own
 instrumentation: the program's spans and module names in a trace
-(``obench/attribution.py``), and the readers of the metrics that the
-program counts (``retry_ms``, ``admission_wait_ms``)."""
+(``obench/attribution.py``) and the readers of the metrics taken from them
+(star programs' device time, idle time in the executor), and the readers of
+the metrics that the program counts (``retry_ms``, ``admission_wait_ms``)."""
 from __future__ import annotations
 
 import sys
@@ -100,10 +101,41 @@ def test_program_spans_in_a_recorded_trace(tmp_path):
     assert any(k.startswith(("serve.", "engine.")) for k in s.idle_by_span)
 
 
-def _window(loop: str, serve: dict) -> harness.Window:
+def _window(loop: str, serve: dict, trace=None, answered: float = 0.0) -> harness.Window:
     return harness.Window(loop=loop, seconds=51.0, setup_s=1.0, latencies_s=[],
-                          completed_in_window=0.0, serve=serve, capacity_retries=0,
-                          compile_requests=0)
+                          completed_in_window=answered, serve=serve, capacity_retries=0,
+                          compile_requests=0, trace=trace)
+
+
+# the by-hand trace holds 2.3 s of jit_fed_star3 and 3.3 s idle in the
+# executor's spans (test_program_spans_attribute_idle_gaps_by_hand); 2.5
+# answers in the window
+@pytest.mark.parametrize("metric, loop, value", [
+    ("star_device_ms_per_query.open", "open", 2.3e3 / 2.5),
+    ("star_device_ms_per_query.closed", "closed", 2.3e3 / 2.5),
+    ("exec_idle_ms_per_query.closed", "closed", 3.3e3 / 2.5),
+])
+def test_trace_attributed_metrics_per_answer(metric, loop, value):
+    read = harness._reader(HERE / "metrics" / f"{metric}.py")
+    s = attribution.reduce([OPS], [MODULES], HARNESS_SPANS + PROGRAM_SPANS)
+    assert read(_window(loop, {}, s, 2.5)) == pytest.approx(value)
+    if metric.startswith("star"):
+        assert read(_window(loop, {}, s, 2.5)) * 2.5 * 1e-3 <= s.busy_s
+    # an untraced run, the other loop, a window with no answer: nothing to read
+    other = "closed" if loop == "open" else "open"
+    assert read(_window(loop, {}, None, 2.5)) is None
+    assert read(_window(other, {}, s, 2.5)) is None
+    assert read(_window(loop, {}, s, 0.0)) is None
+    # the harness's own reduction has no programs or executor spans
+    assert read(_window(loop, {}, tracing.reduce([OPS], HARNESS_SPANS), 2.5)) is None
+    if metric.startswith("star"):
+        # a window in which no star program ran
+        plain = [m for m in MODULES if not m.name.startswith("jit_fed_star")]
+        s = attribution.reduce([OPS], [plain], HARNESS_SPANS + PROGRAM_SPANS)
+        assert read(_window(loop, {}, s, 2.5)) is None
+    cell = {"open": "fedbench9.bound_open", "closed": "largerdf13.heavy_closed"}[loop]
+    assert metric in [m["name"] for m, _ in harness.load_cell(REPO, cell, trace=True).metrics]
+    assert metric not in [m["name"] for m, _ in harness.load_cell(REPO, cell, trace=False).metrics]
 
 
 @pytest.mark.parametrize("metric, loop, key", [
